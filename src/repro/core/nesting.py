@@ -333,6 +333,31 @@ def critical_nested_bits(model_size_mb: float, n: int = 8) -> int:
 # ---------------------------------------------------------------------------
 # Algorithm 1 on a single (K, N) (or batched (..., K, N)) weight
 # ---------------------------------------------------------------------------
+# Largest (K, N) piece quantized or dequantized at once.  A whole
+# full-width layer stack or vocabulary table at once needs more
+# temporaries (the sort buffers of adaptive rounding, the unpacked
+# streams) than one chip holds.  Layers and columns share no scale, CASE
+# flip group or packed word, so pieces give the same codes and weights.
+PIECE_ELEMS = 1 << 24
+
+
+def _pieces(shape):
+    """Cut a (..., K, N) tensor into independent pieces: one per leading
+    index, and blocks of columns for a 2-D tensor of more than
+    PIECE_ELEMS elements.  Returns ([(index, piece shape)], join), where
+    ``join`` puts the per-piece results back together, or None when the
+    tensor is one piece."""
+    if len(shape) > 2:
+        return [(i, shape[1:]) for i in range(shape[0])], jnp.stack
+    K, N = shape
+    cols = max(PIECE_ELEMS // K, 1)
+    if N <= cols:
+        return None
+    return ([((slice(None), slice(j, j + cols)), (K, min(cols, N - j)))
+             for j in range(0, N, cols)],
+            partial(jnp.concatenate, axis=-1))
+
+
 def _split_level(cur: jax.Array, b_hi: int, b_lo: int, rounding: str,
                  group_size: Optional[int]) -> jax.Array:
     """INT-b_lo quantization of INT-b_hi codes / 2^gap (one ladder level).
@@ -371,21 +396,37 @@ def nest_quantize(w: jax.Array, n: int = 8, h: Optional[int] = None,
         bits = (h, n)
     bits = normalize_bits(bits)
     n = bits[-1]
+    if block is None:
+        block = packing.choose_block(w.shape[-2])
+    shape = tuple(w.shape)
+    split = _pieces(shape)
+    if split is not None:
+        pieces, join = split
+        parts = [nest_quantize(w[ix], rounding=rounding, group_size=group_size,
+                               block=block, bits=bits, validate=validate)
+                 for ix, _ in pieces]
+        return NestedTensor(
+            w_base=join([p.w_base for p in parts]),
+            deltas=tuple(join(ds) for ds in zip(*(p.deltas for p in parts))),
+            scale=join([p.scale for p in parts]),
+            shape=shape, bits=bits, block=block)
     w = w.astype(jnp.float32)
 
     # step 1: INT-n quantization, per-output-channel scale (reduced over the
-    # K axis only: stacked layer/expert dims keep their own scales), CASE
-    # flips over K.
+    # K axis), CASE flips over K.
     qmax = 2 ** (n - 1) - 1
     amax = jnp.max(jnp.abs(w), axis=-2, keepdims=True)
     scale = jnp.maximum(amax, 1e-12) / qmax
     v = w / scale
+    del w
     if rounding == "adaptive":
-        vt = jnp.swapaxes(v, -1, -2)          # flip group = reduction axis K
-        w_int = jnp.swapaxes(adaptive_round(vt, n, group_size=group_size), -1, -2)
+        # flip group = reduction axis K
+        w_int = jnp.swapaxes(adaptive_round(jnp.swapaxes(v, -1, -2), n,
+                                            group_size=group_size), -1, -2)
     else:
         lo, hi = int_range(n)
         w_int = jnp.clip(jnp.round(v), lo, hi).astype(jnp.int32)
+    del v
 
     # step 2: walk the ladder top-down: at each adjacent pair quantize the
     # current codes to the lower bitwidth with the chosen rounding and keep
@@ -398,16 +439,14 @@ def nest_quantize(w: jax.Array, n: int = 8, h: Optional[int] = None,
 
     # step 3: block-pack the base codes and every delta stream along K -
     # the layout the Pallas packed/nested/ladder matmul kernels consume.
-    ax = w.ndim - 2
-    if block is None:
-        block = packing.choose_block(w.shape[-2])
+    ax = len(shape) - 2
     widths = delta_bits(bits)
     return NestedTensor(
         w_base=packing.pack_blocked(cur, bits[0], block, axis=ax),
         deltas=tuple(packing.pack_blocked(d, widths[i], block, axis=ax)
                      for i, d in enumerate(deltas)),
         scale=scale,
-        shape=tuple(w.shape),
+        shape=shape,
         bits=bits,
         block=block,
     )
@@ -471,12 +510,23 @@ def nest_quantize_tree(params, n: int = 8, h: Optional[int] = None,
 
 
 def materialize(nested_params, mode: str = "full", dtype=jnp.bfloat16):
-    """Dequantize a nested pytree to dense weights.
+    """Dequantize a nested pytree to dense weights, piece by piece
+    (see PIECE_ELEMS).
 
     ``mode``: 'full' | 'part' | 'rungK' | an int rung index."""
+    def dense(x, rung):
+        split = _pieces(x.shape)
+        if split is None:
+            return x.rung_weight(rung, dtype)
+        pieces, join = split
+        return join([dense(NestedTensor(
+            x.w_base[ix], tuple(d if d is None else d[ix] for d in x.deltas),
+            x.scale[ix], shp, x.bits, x.block, x.rung), rung)
+            for ix, shp in pieces])
+
     def leaf_fn(x):
         if isinstance(x, NestedTensor):
-            return x.rung_weight(mode_to_rung(mode, x.num_rungs), dtype)
+            return dense(x, mode_to_rung(mode, x.num_rungs))
         return x
     return jax.tree_util.tree_map(
         leaf_fn, nested_params, is_leaf=lambda x: isinstance(x, NestedTensor))

@@ -23,15 +23,24 @@ def plan(x, N: int, K: int, block_k: int, use_pallas, interpret: bool):
     than shrinking block_m and multiplying grid steps.  Callers slice
     the kernel output back to the original M rows.  The kernel path
     additionally requires N a multiple of the 128-lane block_n and K a
-    multiple of block_k; otherwise the jnp reference runs on the
-    unpadded input."""
+    multiple of block_k.  A shape that misses it raises on a TPU backend
+    (``use_pallas=None``), so the chip never runs the reference unseen;
+    elsewhere, or with ``use_pallas=False``, the jnp reference runs on
+    the unpadded input."""
+    on_chip = use_pallas is None and jax.default_backend() == "tpu"
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = on_chip
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     M = x2.shape[0]
-    take_kernel = ((use_pallas or interpret) and M > 0
-                   and N % 128 == 0 and K % block_k == 0)
+    tiles = N % 128 == 0 and K % block_k == 0
+    if on_chip and not tiles:
+        raise ValueError(
+            f"packed matmul of shape M={M} K={K} N={N} misses the kernel "
+            f"tile contract (N % 128 == 0, K % block_k == 0 with "
+            f"block_k={block_k}); pass use_pallas=False to run the jnp "
+            "reference")
+    take_kernel = (use_pallas or interpret) and M > 0 and tiles
     if not take_kernel:
         return x2, lead, M, 0, False
     tile = 8 if M <= 128 else 128

@@ -12,8 +12,9 @@ def nested_matmul(x, words_high, words_low, scale, *, n: int, h: int, K: int,
                   interpret: bool = False, out_dtype=None):
     """y = x @ dequant(recompose(words_high, words_low)).
 
-    Pallas on TPU (or interpret=True for validation) when the shapes meet
-    the tile contract; jnp reference elsewhere (the CPU-test fallback).
+    Pallas on TPU (or interpret=True for validation); the jnp reference
+    on other backends or with use_pallas=False.  A TPU shape that misses
+    the tile contract raises (kernels.dispatch.plan).
     """
     N = words_high.shape[-1]
     x2, lead, M, bm, take_kernel = plan(x, N, K, block_k, use_pallas, interpret)
@@ -35,8 +36,9 @@ def ladder_matmul(x, streams, scale, *, bits, K: int,
     ``len(streams)`` resident streams (base + deltas; bits ascending, one
     entry per stream; scale = the rung scale).
 
-    Pallas on TPU (or interpret=True for validation) when the shapes meet
-    the tile contract; jnp reference elsewhere (the CPU-test fallback).
+    Pallas on TPU (or interpret=True for validation); the jnp reference
+    on other backends or with use_pallas=False.  A TPU shape that misses
+    the tile contract raises (kernels.dispatch.plan).
     """
     streams = tuple(streams)
     N = streams[0].shape[-1]

@@ -42,8 +42,9 @@ def packed_matmul(x, words, scale, *, k: int, K: int,
                   block_k: int = DEFAULT_BLOCK_K, use_pallas: bool = None,
                   interpret: bool = False, out_dtype=None):
     """y = x @ dequant(words).  Pallas on TPU (or interpret=True for
-    validation) when the shapes meet the tile contract; jnp reference
-    elsewhere (the CPU-test fallback)."""
+    validation); the jnp reference on other backends or with
+    use_pallas=False.  A TPU shape that misses the tile contract raises
+    (kernels.dispatch.plan)."""
     N = words.shape[-1]
     x2, lead, M, bm, take_kernel = plan(x, N, K, block_k, use_pallas, interpret)
     if take_kernel:

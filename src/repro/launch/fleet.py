@@ -31,6 +31,7 @@ import jax
 
 from ..configs import get_config
 from ..models import make_model
+from .compile_cache import enable_compile_cache
 from .flags import chaos_profile, traffic_parent
 
 K8S_TEMPLATE = """\
@@ -127,6 +128,7 @@ def main(argv=None):
                     help="write a k8s-style indexed Job manifest stub "
                          "for this fleet and exit (no simulation)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.replicas <= 0:
         ap.error(f"--replicas must be > 0, got {args.replicas}")
 

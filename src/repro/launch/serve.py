@@ -56,6 +56,7 @@ from ..configs import get_config
 from ..core import NestQuantStore
 from ..core.nesting import mode_to_rung
 from ..models import make_model
+from .compile_cache import enable_compile_cache
 from .flags import traffic_parent
 
 
@@ -109,6 +110,7 @@ def main(argv=None):
                          "'floor' (per-leaf QualityFloorPolicy floors; "
                          "needs --policy quality)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     spec = None
     if args.speculate:
         from ..api import SpecConfig

@@ -31,6 +31,7 @@ from ..configs import get_config
 from ..data import DataConfig, SyntheticLM
 from ..models import make_model
 from ..optim import adamw
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -50,6 +51,7 @@ def main(argv=None):
     ap.add_argument("--step-deadline-s", type=float, default=120.0)
     ap.add_argument("--simulate-failure-at", type=int, default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -30,10 +30,8 @@ from repro.optim import adamw
 
 cfg = get_config("qwen2-1.5b").reduced()
 shape = ShapeConfig("t", "train", 16, 4, microbatch=2)
-# jax >= 0.7 wants explicit axis_types; 0.4.x has no jax.sharding.AxisType
-mesh_kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-           if hasattr(jax.sharding, "AxisType") else {})
-mesh = jax.make_mesh((2, 2), ("data", "model"), **mesh_kw)
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 jitted, specs = steps_lib.build_train_step(cfg, shape, mesh)
 model = specs["model"]

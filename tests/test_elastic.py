@@ -19,9 +19,7 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.checkpoint import CheckpointManager
 
-# jax >= 0.7 wants explicit axis_types; 0.4.x has no jax.sharding.AxisType
-mesh_kw = ({"axis_types": (jax.sharding.AxisType.Auto,) * 2}
-           if hasattr(jax.sharding, "AxisType") else {})
+mesh_kw = {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
 meshA = jax.make_mesh((2, 2), ("data", "model"), **mesh_kw)
 meshB = jax.make_mesh((1, 4), ("data", "model"), **mesh_kw)
 

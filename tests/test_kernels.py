@@ -230,6 +230,28 @@ def test_dispatch_pads_uneven_m(M):
                                rtol=1e-4, atol=1e-4)
 
 
+def test_dispatch_mis_tiled_shape_raises_on_tpu(monkeypatch):
+    """On a TPU backend a shape that misses the tile contract (N = 96 is
+    not a multiple of 128) raises and names the shape instead of running
+    the jnp reference unseen; use_pallas=False still runs the reference."""
+    from repro.kernels import dispatch
+    from repro.kernels.packed_matmul import ops as pm_ops
+    n, h = 8, 4
+    K, N = 256, 96
+    w, nt = _nested_weight(n, h, K, N, seed=11)
+    x = jnp.asarray(np.random.default_rng(12).normal(size=(4, K))
+                    .astype(np.float32))
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match=rf"M=4 K={K} N={N}"):
+        pm_ops.packed_matmul(x, nt.w_high, nt.part_scale.reshape(1, -1),
+                             k=h, K=K, block_k=nt.block)
+    y = pm_ops.packed_matmul(x, nt.w_high, nt.part_scale.reshape(1, -1),
+                             k=h, K=K, block_k=nt.block, use_pallas=False)
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(x @ nt.part_bit(jnp.float32)),
+                               rtol=1e-4, atol=1e-4)
+
+
 def test_gather_rows_matches_dense_dequant():
     """Packed embedding gather: rows read straight from the words must
     equal indexing the dense dequantized table, in both modes."""

@@ -85,3 +85,24 @@ def test_storage_reduction_close_to_ideal(small_model):
     reduction = 1 - nest_packed / div["total"]
     # ideal (h + l+1)/(n + h) = (4+5)/(8+4) = 25%; packing rounds off a bit
     assert 0.15 < reduction < 0.35
+
+
+@pytest.mark.parametrize("shape", [(3, 64, 96), (128, 200)])
+def test_piecewise_quantize_and_materialize_match_whole(shape, monkeypatch):
+    """Quantizing and dequantizing in pieces (per layer, per column block)
+    gives exactly the codes and weights of the whole tensor at once."""
+    from repro.core import nesting
+    w = jax.random.normal(jax.random.PRNGKey(3), shape, jnp.float32) * 0.05
+
+    def run():
+        nt = nesting.nest_quantize(w, bits=(8, 6, 4))
+        dense = [materialize({"w": nt}, r, jnp.float32)["w"] for r in range(3)]
+        return [nt.w_base, *nt.deltas, nt.scale, *dense]
+
+    monkeypatch.setattr(nesting, "_pieces", lambda shape: None)
+    whole = run()
+    monkeypatch.undo()
+    monkeypatch.setattr(nesting, "PIECE_ELEMS", 64 * 48)
+    assert nesting._pieces(shape[-2:]) is not None     # columns are cut too
+    for a, b in zip(run(), whole):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

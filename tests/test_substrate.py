@@ -192,22 +192,15 @@ def test_grad_compression_error_feedback_unbiased():
     total_comp = jnp.zeros_like(g_true)
     # single-device psum == identity; run the quantize/feedback loop
     import jax
-    # jax >= 0.7 wants explicit axis_types and exposes jax.shard_map;
-    # 0.4.x has neither (jax.sharding.AxisType was removed/renamed and
-    # shard_map still lives in jax.experimental) - guard both
-    mesh_kw = ({"axis_types": (jax.sharding.AxisType.Auto,)}
-               if hasattr(jax.sharding, "AxisType") else {})
-    mesh = jax.make_mesh((1,), ("d",), **mesh_kw)
+    mesh = jax.make_mesh((1,), ("d",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     from jax.sharding import PartitionSpec as P
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
 
     @jax.jit
     def step(g, r):
         def inner(g, r):
             return compress_decompress(g, r, "d")
-        return shard_map(inner, mesh=mesh, in_specs=(P(), P()),
+        return jax.shard_map(inner, mesh=mesh, in_specs=(P(), P()),
                          out_specs=(P(), P()))(g, r)
 
     for _ in range(30):
