@@ -43,6 +43,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import packing
 from .decompose import normalize_bits
 from .nesting import (NestedTensor, check_rung, materialize, mode_to_rung,
@@ -571,33 +572,36 @@ class NestQuantStore:
         ``{'page_in', 'page_out', 'moves'}`` for this call alone."""
         if not isinstance(assignment, RungAssignment):
             assignment = RungAssignment.uniform(assignment)
-        before_in = self.ledger.page_in_bytes
-        before_out = self.ledger.page_out_bytes
-        before_ev = len(self.ledger.events)
-        if assignment.is_uniform and not self.is_mixed:
-            self.to_rung(mode_to_rung(assignment.default, self.num_rungs))
-        else:
-            targets = self.resolve_assignment(assignment)
-            moves = [(p, self._leaf_rungs[p], targets[p])
-                     for p in self._leaf_paths
-                     if targets[p] != self._leaf_rungs[p]]
-            plans = []
-            try:                        # phase 1: stage (no mutation)
-                for path, _, tgt in moves:
-                    plans.append(self._stage_leaf(path, tgt))
-            except BaseException:
-                self._abort_stage(plans)
-                raise
-            for (path, cur, tgt), plan in zip(moves, plans):
-                self._commit_leaf(plan)  # phase 2: commit (cannot fail)
-                self.ledger.record(page_in=plan["pin"],
-                                   page_out=plan["pout"],
-                                   from_rung=cur, to_rung=tgt)
-            self._refresh_summary()
-            self._rebuild_tree()
-        return {"page_in": self.ledger.page_in_bytes - before_in,
-                "page_out": self.ledger.page_out_bytes - before_out,
-                "moves": len(self.ledger.events) - before_ev}
+        to = (mode_to_rung(assignment.default, self.num_rungs)
+              if assignment.is_uniform else -1)
+        with obs.span("switch", from_rung=self.rung, to_rung=to):
+            before_in = self.ledger.page_in_bytes
+            before_out = self.ledger.page_out_bytes
+            before_ev = len(self.ledger.events)
+            if assignment.is_uniform and not self.is_mixed:
+                self.to_rung(to)
+            else:
+                targets = self.resolve_assignment(assignment)
+                moves = [(p, self._leaf_rungs[p], targets[p])
+                         for p in self._leaf_paths
+                         if targets[p] != self._leaf_rungs[p]]
+                plans = []
+                try:                        # phase 1: stage (no mutation)
+                    for path, _, tgt in moves:
+                        plans.append(self._stage_leaf(path, tgt))
+                except BaseException:
+                    self._abort_stage(plans)
+                    raise
+                for (path, cur, tgt), plan in zip(moves, plans):
+                    self._commit_leaf(plan)  # phase 2: commit (cannot fail)
+                    self.ledger.record(page_in=plan["pin"],
+                                       page_out=plan["pout"],
+                                       from_rung=cur, to_rung=tgt)
+                self._refresh_summary()
+                self._rebuild_tree()
+            return {"page_in": self.ledger.page_in_bytes - before_in,
+                    "page_out": self.ledger.page_out_bytes - before_out,
+                    "moves": len(self.ledger.events) - before_ev}
 
     def to_rung(self, rung: int):
         """Walk the whole tree one adjacent rung at a time, fetching /

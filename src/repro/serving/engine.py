@@ -21,6 +21,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..configs.base import ModelConfig
 from ..core.switching import NestQuantStore, RungAssignment
 from ..models.model import Model, make_model
@@ -117,7 +118,6 @@ class EngineStats:
     # excluded here exactly as sched_filler excludes them from admission
     # accounting - a padded batch must not dilute the acceptance rate.
     spec_rounds: int = 0          # draft/verify rounds (= verify passes)
-    spec_draft_steps: int = 0     # draft-rung decode dispatches
     spec_drafted: int = 0         # tokens drafted for real requests
     spec_accepted: int = 0        # drafted tokens accepted (real only)
     spec_rejected: int = 0        # drafted tokens rejected (real only)
@@ -372,36 +372,38 @@ class ServeEngine:
         and KEEPS SERVING at the current residency - the highest rung
         that is actually healthy.  No request is ever dropped because a
         delta stream would not arrive."""
-        quarantined = getattr(self.store.pager, "quarantined", None)
-        signal = self._tracker.signal(
-            memory_budget_bytes=memory_budget_bytes,
-            queue_depth=queue_depth, backlog_age_s=backlog_age_s,
-            available_rung=self.store.max_available_rung(),
-            quarantined=len(quarantined()) if callable(quarantined) else 0,
-            kv_rung=self.kv.rung if self.kv is not None else -1,
-            kv_num_rungs=(self.kv.config.num_rungs
-                          if self.kv is not None else 0),
-            kv_resident_bytes=(self.kv.resident_bytes()
-                               if self.kv is not None else 0))
-        self._ensure_kv_rung(signal)
-        try:
-            report = self.store.apply(self.policy.decide(self.store, signal))
-        except SWITCH_FAILURES as e:
-            self.stats.switch_failures += 1
-            self.stats.last_failure = str(e)
-            self._tracker.note(False, failed=True)
-            if self._params is None:    # first pickup cannot have staged
+        with obs.span("ensure_mode"):
+            quarantined = getattr(self.store.pager, "quarantined", None)
+            signal = self._tracker.signal(
+                memory_budget_bytes=memory_budget_bytes,
+                queue_depth=queue_depth, backlog_age_s=backlog_age_s,
+                available_rung=self.store.max_available_rung(),
+                quarantined=len(quarantined()) if callable(quarantined) else 0,
+                kv_rung=self.kv.rung if self.kv is not None else -1,
+                kv_num_rungs=(self.kv.config.num_rungs
+                              if self.kv is not None else 0),
+                kv_resident_bytes=(self.kv.resident_bytes()
+                                   if self.kv is not None else 0))
+            self._ensure_kv_rung(signal)
+            try:
+                report = self.store.apply(
+                    self.policy.decide(self.store, signal))
+            except SWITCH_FAILURES as e:
+                self.stats.switch_failures += 1
+                self.stats.last_failure = str(e)
+                self._tracker.note(False, failed=True)
+                if self._params is None:    # first pickup cannot have staged
+                    self._params = self.store.params()
+                self.stats.record_mode(self.store.mode)
+                return self.store.mode
+            changed = report["moves"] > 0
+            self._tracker.note(changed)
+            if changed:
+                self.stats.switches += 1
+            if changed or self._params is None:
                 self._params = self.store.params()
             self.stats.record_mode(self.store.mode)
             return self.store.mode
-        changed = report["moves"] > 0
-        self._tracker.note(changed)
-        if changed:
-            self.stats.switches += 1
-        if changed or self._params is None:
-            self._params = self.store.params()
-        self.stats.record_mode(self.store.mode)
-        return self.store.mode
 
     # -- nested KV cache (DESIGN.md Sec. 16) -------------------------------
     def _ensure_kv_rung(self, signal: ResourceSignal) -> None:
@@ -510,7 +512,22 @@ class ServeEngine:
         pass verifies all k+1 positions, and the longest matching prefix
         is accepted - output token ids are bit-identical to this same
         call without ``speculate``.  Either way ``last_profile`` records
-        what was dispatched for the virtual-clock cost model."""
+        what was dispatched for the virtual-clock cost model.
+
+        The call is one ``nq.generate`` span, with the spans of its
+        phases inside (``repro.obs``)."""
+        with obs.span("generate", batch=self.stats.prefills,
+                      rows=len(requests),
+                      real_rows=sum(r.uid >= 0 for r in requests),
+                      prompt_len=max((len(r.prompt) for r in requests),
+                                     default=0),
+                      steps=max((r.max_new_tokens for r in requests),
+                                default=0)) as span:
+            return self._generate(span, requests, memory_budget_bytes,
+                                  queue_depth, backlog_age_s, speculate)
+
+    def _generate(self, span, requests, memory_budget_bytes, queue_depth,
+                  backlog_age_s, speculate) -> List[Request]:
         if len(requests) > self.max_batch:
             raise ValueError(f"batch of {len(requests)} exceeds "
                              f"max_batch={self.max_batch}")
@@ -528,6 +545,7 @@ class ServeEngine:
             memory_budget_bytes,
             queue_depth=len(requests) if queue_depth is None else queue_depth,
             backlog_age_s=backlog_age_s)
+        span.set_metadata(rung=self.store.rung)
         params = self._params
         B = len(requests)
         S = max(len(r.prompt) for r in requests)
@@ -540,33 +558,41 @@ class ServeEngine:
         toks = np.zeros((B, S), np.int32)
         for i, r in enumerate(requests):
             toks[i, S - len(r.prompt):] = r.prompt       # left-pad
-        logits, cache = self._prefill(params, {"tokens": jnp.asarray(toks)})
+        with obs.span("prefill", prompt_len=S):
+            logits, cache = self._prefill(params,
+                                          {"tokens": jnp.asarray(toks)})
         self.stats.prefills += 1
-        # re-home the cache into a max_len buffer
-        full = self.model.make_cache(B, self.max_len,
-                                     dtype=jnp.dtype(self.cfg.compute_dtype))
-        for key, v in cache.items():
-            if key == "pos":
-                full["pos"] = v
-            elif key in ("k", "v") and v.shape[-3] == S:
-                full[key] = jax.lax.dynamic_update_slice(
-                    full[key].astype(v.dtype), v, (0,) * v.ndim)
-            else:
-                full[key] = v
-        cache = full
-        self._kv_ingest(cache, S)
+        with obs.span("cache_rehome"):
+            # re-home the cache into a max_len buffer
+            full = self.model.make_cache(
+                B, self.max_len, dtype=jnp.dtype(self.cfg.compute_dtype))
+            for key, v in cache.items():
+                if key == "pos":
+                    full["pos"] = v
+                elif key in ("k", "v") and v.shape[-3] == S:
+                    full[key] = jax.lax.dynamic_update_slice(
+                        full[key].astype(v.dtype), v, (0,) * v.ndim)
+                else:
+                    full[key] = v
+            cache = full
+            self._kv_ingest(cache, S)
         next_tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
         if spec is not None:
             SpeculativeDecoder(self, spec).decode(
                 requests, params, cache, next_tok, pos=S)
             return requests
-        for _ in range(n_steps):
-            for i, r in enumerate(requests):
-                if len(r.out_tokens) < r.max_new_tokens:
+        for step in range(n_steps):
+            live = [(i, r) for i, r in enumerate(requests)
+                    if len(r.out_tokens) < r.max_new_tokens]
+            with obs.span("token_sync", step=step, rows=len(live)):
+                for i, r in live:
                     r.out_tokens.append(int(next_tok[i, 0]))
-            logits, cache = self._decode(params, {"tokens": next_tok}, cache)
+            with obs.span("decode_step", step=step):
+                logits, cache = self._decode(params, {"tokens": next_tok},
+                                             cache)
+                next_tok = jnp.argmax(logits[:, -1, :],
+                                      axis=-1)[:, None].astype(jnp.int32)
             self.stats.decode_steps += 1
-            next_tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
         self.last_profile = DecodeProfile(
             steps=n_steps, verify_bytes=self.store.resident_bytes())
         return requests
@@ -665,7 +691,6 @@ class SpeculativeDecoder:
             pos += m + 1
             cache["pos"] = jnp.asarray(pos, jnp.int32)
         stats.spec_rounds += rounds
-        stats.spec_draft_steps += draft_steps
         stats.spec_drafted += drafted
         stats.spec_accepted += accepted
         stats.spec_rejected += drafted - accepted

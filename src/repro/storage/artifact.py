@@ -40,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core.nesting import NestedTensor
 
 MANIFEST = "manifest.json"
@@ -289,7 +290,8 @@ class Artifact:
             if not self.segment_available(spec["segment"]):
                 raise ArtifactError(
                     f"segment {spec['segment']!r} not delivered yet")
-            with open(self.segment_path(spec["segment"]), "rb") as f:
+            with obs.span("page_in.read", nbytes=spec["nbytes"]), \
+                    open(self.segment_path(spec["segment"]), "rb") as f:
                 f.seek(spec["offset"])
                 raw = f.read(spec["nbytes"])
             self._count(spec["segment"], len(raw))
@@ -297,7 +299,8 @@ class Artifact:
             raise ArtifactError(f"short read in {spec['segment']!r} at "
                                 f"offset {spec['offset']}")
         if verify:
-            observed = zlib.crc32(raw)
+            with obs.span("page_in.crc", nbytes=len(raw)):
+                observed = zlib.crc32(raw)
             if observed != spec["crc32"]:
                 from .pager import CorruptStreamError   # lazy: no cycle
                 raise CorruptStreamError(

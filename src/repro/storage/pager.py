@@ -56,6 +56,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from .artifact import ArtifactError
 
 
@@ -270,7 +271,8 @@ class FilePager:
                 f"delta stream corrupted: leaf {path!r} level {level}: "
                 f"{e}") from e
         self._resident[(path, level)] = spec["nbytes"]
-        return jnp.asarray(arr)
+        with obs.span("page_in.put", nbytes=spec["nbytes"]):
+            return jnp.asarray(arr)
 
     def expected_crc(self, path: str, level: int) -> Optional[int]:
         """The manifest's recorded CRC-32 for one delta stream."""
