@@ -21,9 +21,12 @@ The spans, what each covers, and its arguments:
 - ``nq.prefill``: the prefill dispatch.  ``prompt_len``.
 - ``nq.cache_rehome``: the prompt's cache moved into the ``max_len``
   buffer, and the nested KV cache's ingest.
-- ``nq.token_sync``: the per-row pulls of one step's tokens to the host.
-  ``step``, ``rows``.
-- ``nq.decode_step``: one decode dispatch and its argmax.  ``step``.
+- ``nq.token_sync``: one transfer of the step's tokens, one step
+  behind: the host reads the tokens of step ``step - 1`` (the prefill's
+  for step 0) once the decode of ``step`` is queued, and hands them to
+  the live rows.  ``step``, ``rows``.
+- ``nq.decode_step``: one decode dispatch and its argmax; it opens
+  before the same step's ``nq.token_sync``.  ``step``.
 - ``nq.switch``: ``NestQuantStore.apply``.  ``from_rung``, ``to_rung``
   (-1 for a per-leaf assignment).
 - ``nq.page_in.read``: one array's bytes read from its segment file

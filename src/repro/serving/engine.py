@@ -125,6 +125,9 @@ class EngineStats:
     kv_switches: int = 0          # committed cache rung moves
     kv_switch_failures: int = 0   # cache switch attempts rolled back
     kv_pages: int = 0             # pages ingested over the engine's life
+    # device->host transfers of decoded tokens: one per plain decode step,
+    # whatever the number of rows
+    token_pulls: int = 0
 
     @property
     def spec_acceptance(self) -> float:
@@ -581,18 +584,26 @@ class ServeEngine:
             SpeculativeDecoder(self, spec).decode(
                 requests, params, cache, next_tok, pos=S)
             return requests
+        # The host reads each step's tokens one step behind: step t's
+        # decode is queued on the device before the host waits for the
+        # tokens step t-1 produced, in one transfer for all rows.
+        next_tok.copy_to_host_async()
         for step in range(n_steps):
-            live = [(i, r) for i, r in enumerate(requests)
-                    if len(r.out_tokens) < r.max_new_tokens]
-            with obs.span("token_sync", step=step, rows=len(live)):
-                for i, r in live:
-                    r.out_tokens.append(int(next_tok[i, 0]))
             with obs.span("decode_step", step=step):
                 logits, cache = self._decode(params, {"tokens": next_tok},
                                              cache)
-                next_tok = jnp.argmax(logits[:, -1, :],
-                                      axis=-1)[:, None].astype(jnp.int32)
+                nxt = jnp.argmax(logits[:, -1, :],
+                                 axis=-1)[:, None].astype(jnp.int32)
+            nxt.copy_to_host_async()
             self.stats.decode_steps += 1
+            live = [(i, r) for i, r in enumerate(requests)
+                    if len(r.out_tokens) < r.max_new_tokens]
+            with obs.span("token_sync", step=step, rows=len(live)):
+                host = np.asarray(next_tok)
+                self.stats.token_pulls += 1
+                for i, r in live:
+                    r.out_tokens.append(int(host[i, 0]))
+            next_tok = nxt
         self.last_profile = DecodeProfile(
             steps=n_steps, verify_bytes=self.store.resident_bytes())
         return requests

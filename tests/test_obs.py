@@ -90,6 +90,24 @@ def test_generate_records_one_span_with_a_token_sync_and_step_per_step(
                 if s[0] == name] == [True]
 
 
+def test_each_decode_step_is_queued_before_the_host_waits_for_tokens(
+        artifact, tmp_path):
+    cfg = artifact[0]
+    eng = _engine(artifact)
+    eng.generate(_reqs(cfg))            # compile outside the session
+    _traced(tmp_path, lambda: eng.generate(_reqs(cfg)))
+    spans = _spans(tmp_path)
+    opens = {(s[0], s[3]["step"]): s[1] for s in spans
+             if s[0] in ("nq.decode_step", "nq.token_sync")}
+    assert len(opens) == 2 * STEPS
+    for step in range(STEPS):
+        assert (opens[("nq.decode_step", step)]
+                < opens[("nq.token_sync", step)])
+        if step:
+            assert (opens[("nq.token_sync", step - 1)]
+                    < opens[("nq.decode_step", step)])
+
+
 def test_upgrade_through_the_file_pager_records_a_triple_per_stream(
         artifact, tmp_path):
     eng = _engine(artifact)

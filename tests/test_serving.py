@@ -35,6 +35,66 @@ def test_generate_produces_tokens(engine):
     assert eng.stats.prefills == 1 and eng.stats.decode_steps == 4
 
 
+@pytest.fixture(scope="module")
+def ladder_engine():
+    cfg = get_config("qwen2-1.5b").reduced()
+    params = make_model(cfg).init(jax.random.PRNGKey(0))
+    store = NestQuantStore(nest_quantize_tree(params, bits=(8, 6, 4)),
+                           mode="part", dtype=jnp.float32)
+    return cfg, ServeEngine(cfg, store, max_batch=4, max_len=48), store
+
+
+def _reference_tokens(cfg, model, params, reqs, max_len):
+    """Plain greedy loop with no pipelining: prefill, argmax, then
+    decode_step and argmax, with one ``int()`` per live row per step."""
+    S = max(len(r.prompt) for r in reqs)
+    toks = np.zeros((len(reqs), S), np.int32)
+    for i, r in enumerate(reqs):
+        toks[i, S - len(r.prompt):] = r.prompt
+    logits, short = jax.jit(model.prefill)(params,
+                                           {"tokens": jnp.asarray(toks)})
+    cache = model.make_cache(len(reqs), max_len,
+                             dtype=jnp.dtype(cfg.compute_dtype))
+    cache["pos"] = short["pos"]
+    for key in ("k", "v"):
+        cache[key] = jax.lax.dynamic_update_slice(
+            cache[key].astype(short[key].dtype), short[key],
+            (0,) * short[key].ndim)
+    decode = jax.jit(model.decode_step)
+    tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
+    out = [[] for _ in reqs]
+    for _ in range(max(r.max_new_tokens for r in reqs)):
+        for i, r in enumerate(reqs):
+            if len(out[i]) < r.max_new_tokens:
+                out[i].append(int(tok[i, 0]))
+        logits, cache = decode(params, {"tokens": tok}, cache)
+        tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
+    return out
+
+
+@pytest.mark.parametrize("rung", [0, 1, 2])
+def test_pipelined_tokens_match_a_plain_loop_at_every_rung(ladder_engine,
+                                                           rung):
+    """Tokens read one step behind, in one transfer a step, are the
+    tokens of the unpipelined loop, row by row, with ragged answer
+    lengths; and the host pulls once a step, not once a row a step."""
+    cfg, eng, store = ladder_engine
+    rng = np.random.default_rng(rung)
+    reqs = [Request(i, rng.integers(0, cfg.vocab_size, S).astype(np.int32),
+                    max_new_tokens=n)
+            for i, (S, n) in enumerate([(6, 1), (4, 5), (6, 3), (5, 4)])]
+    budget = None if rung == 2 else store.rung_resident_bytes(rung)
+    steps0, pulls0 = eng.stats.decode_steps, eng.stats.token_pulls
+    eng.generate(reqs, memory_budget_bytes=budget)
+    assert store.rung == rung
+    assert [len(r.out_tokens) for r in reqs] == [1, 5, 3, 4]
+    want = _reference_tokens(cfg, eng.model, store.params(), reqs,
+                             eng.max_len)
+    assert [r.out_tokens for r in reqs] == want
+    assert eng.stats.decode_steps - steps0 == 5
+    assert eng.stats.token_pulls - pulls0 == 5
+
+
 def test_budget_switching(engine):
     cfg, eng, store = engine
     b = store.bytes()
