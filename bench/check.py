@@ -2,7 +2,8 @@
 
 The served tokens of a sample of finished requests, drawn from the
 seed with the longest answer of each served rung in it, are scored by
-the plain reference (``reference.py``): each request alone, its prompt
+the plain reference of the configuration's architecture (its module's
+``score``, found by ``arch.load``): each request alone, its prompt
 and its served tokens, with the weights of the rung that served it.  At
 every served position the number read is the gap by which the served
 token's reference logit lies below the reference's best; a rung's
@@ -10,7 +11,8 @@ numbers are the widest gap over its sample and the mean gap.  The
 configuration's ``limits`` say which of them each cell compares.  Exact
 checks go beside it: every token stamped once, every token a vocabulary
 id, every answer as long as asked, and every switch a ledgered adjacent
-move of exactly bytes(delta_k) as computed from the shapes.
+move of exactly bytes(delta_k) as computed from the architecture's
+quantized leaves.
 
 The control puts the reference at a lower precision (the
 configuration's ``control_bits`` per rung, plain round-to-nearest) in
@@ -20,12 +22,11 @@ same limits.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Dict, List, Tuple
 
 import numpy as np
 
-import reference
+import arch
 import shapes
 
 
@@ -57,7 +58,7 @@ def row(r: Dict) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     return toks, np.arange(S - 1, S - 1 + len(out)), out[:, None]
 
 
-def request_faults(r: Dict, s: shapes.Sizes) -> Dict[str, bool]:
+def request_faults(r: Dict, s) -> Dict[str, bool]:
     """What is wrong with one served request's answer, checked exactly."""
     out = r["req"].out_tokens
     return {"stamp_mismatch": len(out.stamps) != len(out),
@@ -65,13 +66,16 @@ def request_faults(r: Dict, s: shapes.Sizes) -> Dict[str, bool]:
             "short_answers": len(out) != r["req"].max_new_tokens}
 
 
-def exact_failures(r: Dict, s: shapes.Sizes) -> int:
+def exact_failures(r: Dict, s) -> int:
     return int(any(request_faults(r, s).values()))
 
 
-def exact(w, s: shapes.Sizes, bits) -> Dict[str, int]:
+def exact(w, config: Dict) -> Dict[str, int]:
     """Counts that must be 0: served requests with each fault, and
     switches that were not an adjacent move of bytes(delta_k)."""
+    a = arch.load(config)
+    s = a.sizes(config)
+    leaves, bits = a.quantized_leaves(s), config["quant_bits"]
     counts = {"stamp_mismatch": 0, "bad_token_ids": 0, "short_answers": 0}
     for r in w.requests:
         if r["rung"] is not None:
@@ -80,7 +84,7 @@ def exact(w, s: shapes.Sizes, bits) -> Dict[str, int]:
     moves = 0
     for sw in w.switches:
         for frm, to, pin, pout in sw["events"]:
-            want = shapes.delta_bytes(s, bits, min(frm, to))
+            want = shapes.delta_bytes(leaves, bits, min(frm, to))
             moved, other = (pin, pout) if to > frm else (pout, pin)
             moves += abs(to - frm) != 1 or moved != want or other != 0
         moves += sw["rung_after"] != sw["to"]
@@ -96,10 +100,13 @@ def numbers(w, seed: int, config: Dict, per_rung: int,
     if config["quant_rounding"] != "rtn":
         raise ValueError("the reference nests with round-to-nearest codes; "
                          f"the configuration states {config['quant_rounding']}")
-    s = shapes.Sizes.from_config(config)
+    a = arch.load(config)
+    s = a.sizes(config)
     bits = tuple(sorted(config["quant_bits"]))
-    ref = partial(reference.score, s=s, wcfg=config["weights"],
-                  seed=config["weight_seed"])
+
+    def ref(rows, quant):
+        return a.score(rows, s, config["weights"], config["weight_seed"],
+                       quant)
     out: Dict[str, float] = {}
     for rung, rs in sample(w, seed, per_rung).items():
         rows = [row(r) for r in rs]
@@ -124,9 +131,8 @@ def judge(w, seed: int, config: Dict, per_rung: int,
 
 def compare(w, config: Dict, got: Dict[str, float]) -> Dict[str, Dict]:
     """The exact counts (limit 0) and the ``limits`` of ``got``."""
-    s = shapes.Sizes.from_config(config)
     checks = {k: {"value": v, "limit": 0}
-              for k, v in exact(w, s, config["quant_bits"]).items()}
+              for k, v in exact(w, config).items()}
     for name, limit in sorted(config["limits"].items()):
         if name in got:
             checks[name] = {"value": got[name], "limit": limit}

@@ -19,11 +19,9 @@ it on the host, so a stamp is when a streaming client could have it.
 """
 from __future__ import annotations
 
-import dataclasses
 import gc
 import json
 import os
-import sys
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -31,13 +29,12 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-import shapes
+import arch
 import traffic
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 CACHE = BENCH / ".cache"
-PROGRAM_EPS = 1e-6       # built into the program's RMSNorm; not a setting
 
 
 class StampedList(list):
@@ -75,8 +72,13 @@ class Cell:
     per_layer: List[Dict]
 
     @property
-    def sizes(self) -> shapes.Sizes:
-        return shapes.Sizes.from_config(self.config)
+    def arch(self):
+        """The configuration's architecture module (``bench/arch/``)."""
+        return arch.load(self.config)
+
+    @property
+    def sizes(self):
+        return self.arch.sizes(self.config)
 
 
 def _applies(metric: Dict, cell: str) -> bool:
@@ -101,28 +103,6 @@ def load_cell(workload: str) -> Cell:
 # ---------------------------------------------------------------------------
 # set-up
 # ---------------------------------------------------------------------------
-def program_config(config: Dict):
-    """The program's model config for a configuration file: its family
-    from ``program_arch``, every size from the file."""
-    from repro.configs import get_config
-    s = shapes.Sizes.from_config(config)
-    pc = dataclasses.replace(
-        get_config(config["program_arch"]), num_layers=s.layers,
-        d_model=s.d, d_ff=s.ff, num_heads=s.heads, num_kv_heads=s.kv_heads,
-        head_dim=s.head_dim, vocab_size=s.vocab, rope_theta=s.rope_theta,
-        qkv_bias=s.qkv_bias, tie_embeddings=False)
-    have = (pc.family, pc.act, pc.norm, pc.compute_dtype)
-    want = ("dense", "swiglu", "rmsnorm", config["torch_dtype"])
-    if have != want:
-        raise SystemExit(f"the program serves {have}; the configuration "
-                         f"file states {want}")
-    if s.eps != PROGRAM_EPS:
-        print(f"[config] the program's RMSNorm eps is {PROGRAM_EPS:g}; the "
-              f"configuration states {s.eps:g}, which the reference uses",
-              file=sys.stderr, flush=True)
-    return pc
-
-
 def artifact_dir(config: Dict) -> Path:
     bits = "-".join(str(b) for b in config["quant_bits"])
     return CACHE / "artifacts" / (f"{config['name']}-w{config['weight_seed']}"
@@ -135,15 +115,14 @@ def build_artifact(config: Dict, path: Path) -> None:
     is the bf16 tree plus one leaf's work), and save the artifact the
     deployed engine boots from."""
     import jax
-    from repro.api import QuantRecipe, quantize, save_artifact
-    from weights import program_params
-    s = shapes.Sizes.from_config(config)
-    params = jax.jit(lambda: program_params(config["weight_seed"], s,
-                                            config["weights"]))()
+    from repro.api import quantize, save_artifact
+    a = arch.load(config)
+    s = a.sizes(config)
+    params = jax.jit(lambda: a.program_params(config["weight_seed"], s,
+                                              config["weights"]))()
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     del params
-    recipe = QuantRecipe(bits=tuple(config["quant_bits"]),
-                         rounding=config["quant_rounding"])
+    recipe = a.recipe(config)
     packed = []
     for i, (where, _) in enumerate(flat):
         keys = [p.key for p in where]
@@ -228,7 +207,7 @@ class Server:
         if self.cold:
             build_artifact(cell.config, art)
         self.engine = ServeEngine.from_artifact(
-            program_config(cell.config), str(art),
+            cell.arch.program_config(cell.config), str(art),
             max_batch=self.mix["max_batch"], max_len=self.mix["max_len"])
         self.store = self.engine.store
         self.top = self.store.num_rungs - 1

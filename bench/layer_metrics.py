@@ -11,7 +11,6 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional
 
 import measure
-import shapes
 import devtrace as tr
 
 DECODE_MODULE = "decode_step"
@@ -24,7 +23,8 @@ KERNELS = ("packed_matmul", "nested_matmul", "ladder_matmul")
 class Context:
     window: object                  # harness.Window
     B: int                          # batch rows per step
-    sizes: shapes.Sizes
+    arch: object                    # the configuration's bench/arch module
+    sizes: object                   # arch.sizes() of the configuration
     bits: tuple
     peaks: Optional[Dict] = None    # this device's row of peaks.json
     events: Optional[Dict] = None   # trace.load() of the traced run
@@ -60,7 +60,7 @@ def step_mfu(ctx: Context) -> Optional[float]:
     mods = ctx.decode_modules()
     if not mods:
         return None
-    flops = sum(shapes.decode_flops(ctx.sizes, b.rows, b.steps)
+    flops = sum(ctx.arch.decode_flops(ctx.sizes, b.rows, b.steps)
                 for b in ctx.traced_batches())
     secs = sum(d for _, _, d in mods) / 1e9
     return 100.0 * flops / (secs * ctx.peaks["bf16_flops"]) if flops else None
@@ -74,7 +74,7 @@ def kernel_roofline(ctx: Context) -> Optional[float]:
         return None
     kernel_ns = tr.ops_inside(ctx.events, mods,
                               lambda n: n.split(".")[0] in KERNELS)
-    least = sum(b.steps * shapes.decode_step_least_s(
+    least = sum(b.steps * ctx.arch.decode_step_least_s(
         ctx.sizes, ctx.bits, b.rung, ctx.B, ctx.peaks)
         for b in ctx.traced_batches())
     return 100.0 * least / (kernel_ns / 1e9) if kernel_ns > 0 else None

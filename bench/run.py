@@ -101,8 +101,8 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: int,
             breakdown = {"device_ops": reduced["device_ops"],
                          "idle_gaps": reduced["idle_gaps"]}
             ctx = layer_metrics.Context(
-                w, server.mix["max_batch"], cell.sizes, server.bits,
-                peaks_for(device["kind"]), events, reduced)
+                w, server.mix["max_batch"], cell.arch, cell.sizes,
+                server.bits, peaks_for(device["kind"]), events, reduced)
             for m in cell.per_layer:
                 v = reader(m["name"])(ctx)
                 if v is not None:

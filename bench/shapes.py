@@ -1,9 +1,12 @@
 """Sizes of a configuration file, and the operations and bytes its work needs.
 
 This is the benchmark's yardstick: every byte and FLOP count that a
-roofline or utilization metric divides by is computed here from the
-configuration's shapes, never read from the program under test.  The
-packing arithmetic mirrors the stored layout of a NestQuant ladder: a
+roofline or utilization metric divides by is computed from the
+configuration's shapes, never read from the program under test.
+``Sizes``, ``decode_step_least_s``, ``token_flops`` and ``decode_flops``
+are the dense decoder's, called through ``arch/dense.py``; the packing
+arithmetic below them serves every architecture.  It mirrors the stored
+layout of a NestQuant ladder: a
 ``bits[0]``-bit base stream plus one ``(gap + 1)``-bit delta stream per
 rung, each split into power-of-two bit planes packed into 32-bit words
 along the reduction axis K, in blocks of ``block`` rows.
@@ -93,10 +96,12 @@ def weight_bytes(K: int, N: int, bits: Sequence[int], rung: int) -> int:
     return sum(stream_bytes(K, N, w) for w in stream_widths(bits)[:rung + 1])
 
 
-def delta_bytes(s: Sizes, bits: Sequence[int], k: int) -> int:
-    """bytes(delta_k): what a switch between rungs k and k + 1 moves."""
+def delta_bytes(leaves: Sequence[Tuple[int, int, int]], bits: Sequence[int],
+                k: int) -> int:
+    """bytes(delta_k): what a switch between rungs k and k + 1 moves, for
+    quantized leaves given as (K, N, count)."""
     w = stream_widths(bits)[1 + k]
-    return sum(stream_bytes(K, N, w) * n for K, N, n in s.quantized_leaves())
+    return sum(stream_bytes(K, N, w) * n for K, N, n in leaves)
 
 
 def matmul_least_s(M: int, K: int, N: int, bits: Sequence[int], rung: int,

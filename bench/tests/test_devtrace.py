@@ -55,8 +55,8 @@ def test_op_names_and_self_time():
 def test_recorded_chip_trace():
     """Three decode steps of qwen2-1.5b at rung 2, batch 32, recorded on
     one TPU v5e (``tool.py trace``) and cut to a slice."""
+    import arch
     import layer_metrics as lm
-    import shapes
     import traffic
     from conftest import BENCH
     from harness import Batch, Window
@@ -68,7 +68,8 @@ def test_recorded_chip_trace():
     w = Window(0.0, 1.0)
     w.batches = [Batch(0, 2, 0.0, 3, [(512, 256)] * 32, traced=True)]
     cfg = traffic.load(BENCH / "configs" / "qwen2-1.5b.json")
-    ctx = lm.Context(w, 32, shapes.Sizes.from_config(cfg), (8, 6, 4),
+    dense = arch.load(cfg)
+    ctx = lm.Context(w, 32, dense, dense.sizes(cfg), (8, 6, 4),
                      {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}, ev, r)
     assert lm.decode_step_ms(ctx) == pytest.approx(24.185, abs=0.01)
     assert 5 < lm.kernel_roofline(ctx) < 100

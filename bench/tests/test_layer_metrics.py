@@ -1,6 +1,7 @@
 """Per-layer readers: found by name, silent where they have nothing to read."""
 import pytest
 
+import arch
 import layer_metrics as lm
 import run
 import shapes
@@ -8,8 +9,9 @@ import traffic
 from conftest import BENCH
 from harness import Batch, Window
 
-TINY = shapes.Sizes.from_config(traffic.load(BENCH / "tests" / "data" /
-                                             "tiny.json"))
+TINY_CONFIG = traffic.load(BENCH / "tests" / "data" / "tiny.json")
+DENSE = arch.load(TINY_CONFIG)
+TINY = DENSE.sizes(TINY_CONFIG)
 PEAKS = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -20,7 +22,7 @@ def _ctx(events=None):
     if events:
         import devtrace
         reduced = devtrace.reduce(events)
-    return lm.Context(w, 4, TINY, (8, 6, 4), PEAKS, events, reduced)
+    return lm.Context(w, 4, DENSE, TINY, (8, 6, 4), PEAKS, events, reduced)
 
 
 def _events():

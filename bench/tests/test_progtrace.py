@@ -8,15 +8,16 @@ import pytest
 
 import devtrace as tr
 import layer_metrics as lm
+import arch
 import progtrace as pt
-import shapes
 import traffic
 from conftest import BENCH
 from harness import Batch, Window
 
 DATA = BENCH / "tests" / "data"
-QWEN = shapes.Sizes.from_config(traffic.load(BENCH / "configs" /
-                                             "qwen2-1.5b.json"))
+QWEN_CONFIG = traffic.load(BENCH / "configs" / "qwen2-1.5b.json")
+DENSE = arch.load(QWEN_CONFIG)
+QWEN = DENSE.sizes(QWEN_CONFIG)
 PEAKS = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -49,7 +50,7 @@ def _ev():
 
 def _ctx(ev, reduced=None):
     w = Window(0.0, 1.0)
-    return lm.Context(w, 32, QWEN, (8, 6, 4), PEAKS, ev,
+    return lm.Context(w, 32, DENSE, QWEN, (8, 6, 4), PEAKS, ev,
                       reduced or tr.reduce(ev))
 
 
@@ -100,8 +101,8 @@ def test_readers_are_silent_without_program_spans():
     no_switch = {**_ev(), "program": [s for s in _ev()["program"]
                                       if not s[0].startswith("nq.page")]}
     assert pt.switch_share(_ctx(no_switch), "read") is None
-    assert pt.token_sync_idle(lm.Context(Window(0.0, 1.0), 32, QWEN,
-                                         (8, 6, 4), PEAKS)) is None
+    assert pt.token_sync_idle(lm.Context(Window(0.0, 1.0), 32, DENSE,
+                                         QWEN, (8, 6, 4), PEAKS)) is None
 
 
 def test_program_spans_change_no_harness_reading(tmp_path):
@@ -125,8 +126,9 @@ def test_program_spans_change_no_harness_reading(tmp_path):
     w.batches = [Batch(0, 2, 0.0, 3, [(512, 256)] * 32, traced=True)]
     for f in (lm.decode_step_ms, lm.kernel_roofline, lm.step_mfu,
               lm.device_idle):
-        a = f(lm.Context(w, 32, QWEN, (8, 6, 4), PEAKS, ev, r))
-        b = f(lm.Context(w, 32, QWEN, (8, 6, 4), PEAKS, back, tr.reduce(back)))
+        a = f(lm.Context(w, 32, DENSE, QWEN, (8, 6, 4), PEAKS, ev, r))
+        b = f(lm.Context(w, 32, DENSE, QWEN, (8, 6, 4), PEAKS, back,
+                         tr.reduce(back)))
         assert a == b
 
 
@@ -149,7 +151,7 @@ def test_a_reader_finds_the_runs_own_trace(tmp_path, monkeypatch):
     (_, a, d), = [s for s in bench if s[0] == "bench.traced"]
 
     def ctx(t0):
-        return lm.Context(Window(0.0, 1.0), 32, QWEN, (8, 6, 4), PEAKS,
+        return lm.Context(Window(0.0, 1.0), 32, DENSE, QWEN, (8, 6, 4), PEAKS,
                           {"ops": [], "spans": bench, "modules": []},
                           {"t0": t0, "t1": a + d})
     found = ctx(a)

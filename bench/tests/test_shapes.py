@@ -29,7 +29,8 @@ def test_delta_bytes_match_the_stored_ladder():
     params = jax.jit(lambda: program_params(0, s, TINY["weights"]))()
     store = NestQuantStore(quantize(params, QuantRecipe(bits=(8, 6, 4))))
     for k in range(2):
-        assert shapes.delta_bytes(s, (8, 6, 4), k) == store.delta_bytes(k)
+        assert shapes.delta_bytes(s.quantized_leaves(), (8, 6, 4),
+                                  k) == store.delta_bytes(k)
 
 
 def test_token_and_decode_flops_by_hand():

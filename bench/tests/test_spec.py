@@ -36,3 +36,13 @@ def test_every_cell_reports_enough(cell):
     layer = [m for m in SPEC["per_layer"] if applies(m)]
     assert layer
     assert all(m["moves"] in e2e for m in layer)
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in SPEC["configs"]])
+def test_every_configuration_resolves_to_an_architecture_file(config):
+    import arch
+    c = {x["name"]: x for x in SPEC["configs"]}[config]
+    cfg = traffic.load(BENCH.parent / c["file"])
+    path = BENCH / "arch" / f"{cfg.get('arch', arch.DEFAULT)}.py"
+    assert path.is_file()
+    assert arch.load(cfg).__file__ == str(path)
