@@ -13,6 +13,7 @@ from .musicgen_large import CONFIG as musicgen_large
 from .zamba2_2_7b import CONFIG as zamba2_2_7b
 from .mamba2_780m import CONFIG as mamba2_780m
 from .phi_3_vision_4_2b import CONFIG as phi_3_vision_4_2b
+from .mellum2_12b_a2_5b import CONFIG as mellum2_12b_a2_5b
 
 ARCHS = {
     c.name: c
@@ -27,6 +28,7 @@ ARCHS = {
         zamba2_2_7b,
         mamba2_780m,
         phi_3_vision_4_2b,
+        mellum2_12b_a2_5b,
     ]
 }
 

@@ -29,6 +29,20 @@ class ModelConfig:
     # --- attention details ---
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    # --- layer pattern: window and full attention side by side ---
+    # ``layer_types`` is one period of the pattern, repeated over the
+    # layers ("sliding" or "full"); empty means every layer is full.
+    # Sliding layers see the ``sliding_window`` latest positions, the
+    # query's own included.  Full layers take YaRN RoPE when
+    # ``yarn_factor`` is set (the ``transformers`` definition: factor,
+    # original context, beta_fast, beta_slow, attention factor).
+    layer_types: Tuple[str, ...] = ()
+    sliding_window: int = 0
+    yarn_factor: float = 0.0
+    yarn_original_max_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
     # --- block details ---
     act: str = "swiglu"            # swiglu | gelu
     norm: str = "rmsnorm"          # rmsnorm | layernorm
@@ -37,6 +51,10 @@ class ModelConfig:
     num_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    # the experts this device holds: [expert_first, expert_first +
+    # experts_held) of the num_experts the router scores (0 = all)
+    expert_first: int = 0
+    experts_held: int = 0
     # --- SSM (mamba2 / zamba2 trunk) ---
     ssm_state: int = 0
     ssm_headdim: int = 64
@@ -67,6 +85,17 @@ class ModelConfig:
     def attn_out_dim(self) -> int:
         return self.num_heads * self.head_dim
 
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights this device holds."""
+        return self.experts_held or self.num_experts
+
+    def layer_type(self, layer: int) -> str:
+        """``"sliding"`` or ``"full"`` for layer ``layer``."""
+        if not self.layer_types:
+            return "full"
+        return self.layer_types[layer % len(self.layer_types)]
+
     def param_count(self) -> int:
         """Closed-form parameter count estimate (matmul weights only)."""
         d, ff, V = self.d_model, self.d_ff, self.vocab_size
@@ -80,7 +109,7 @@ class ModelConfig:
             kvd = self.num_kv_heads * self.head_dim
             attn = d * qd + 2 * d * kvd + qd * d
             if self.family == "moe":
-                mlp = self.num_experts * (3 * d * ff) + d * self.num_experts
+                mlp = self.held_experts * (3 * d * ff) + d * self.num_experts
             else:
                 mlp = 3 * d * ff if self.act == "swiglu" else 2 * d * ff
             n += L * (attn + mlp)
@@ -103,11 +132,18 @@ class ModelConfig:
 
     # ------------------------------------------------------------------
     def reduced(self) -> "ModelConfig":
-        """Tiny same-family variant for CPU smoke tests."""
+        """Tiny same-family variant for CPU smoke tests.  A layer pattern
+        keeps one whole period, its window (8 positions) and its YaRN
+        (original context 16)."""
+        layers = 2 if self.hybrid_attn_every == 0 else 4
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            num_layers=2 if self.hybrid_attn_every == 0 else 4,
+            num_layers=len(self.layer_types) or layers,
+            sliding_window=8 if self.sliding_window else 0,
+            yarn_original_max_len=16 if self.yarn_factor else 0,
+            expert_first=0,
+            experts_held=0,
             d_model=64,
             num_heads=4 if self.num_heads else 0,
             num_kv_heads=min(self.num_kv_heads, 2) if self.num_heads else 0,

@@ -179,6 +179,8 @@ def cache_pspecs(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh) -> Dict[str, 
         else:
             out["k"] = P(None, b, seq_ax, kvh, None)
             out["v"] = P(None, b, seq_ax, kvh, None)
+        if cfg.family == "moe" and shape.kind == "decode":
+            out["expert_rows"] = P()     # make_cache's count; prefill has none
     if cfg.family in ("ssm", "hybrid"):
         h_ax = "model" if cfg.ssm_heads >= mesh.shape["model"] else None
         out["state"] = P(None, b, h_ax, None, None)

@@ -141,8 +141,9 @@ def build_prefill_step(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh):
 
 def _nested_pspecs(nested_abs, dense_pspecs):
     """PartitionSpecs for a NestQuant-packed parameter tree: packed words
-    and scales shard the output-channel dim like the dense weight; the
-    packed K dim stays unsharded (word rows are not evenly divisible)."""
+    and scales shard the output-channel dim, and a stacked expert leaf's
+    expert dim, like the dense weight; the packed K dim stays unsharded
+    (word rows are not evenly divisible)."""
     from jax.sharding import PartitionSpec as P
 
     from ..core.nesting import NestedTensor
@@ -150,8 +151,9 @@ def _nested_pspecs(nested_abs, dense_pspecs):
     def f(leaf, spec):
         if isinstance(leaf, NestedTensor):
             nd = leaf.w_base.ndim
-            out_ax = spec[-1] if len(spec) else None
-            packed = P(*([None] * (nd - 1)), out_ax)
+            spec = tuple(spec) + (None,) * (nd - len(spec))
+            lead = spec[:-2] if nd == 4 else (None,) * (nd - 2)
+            packed = P(*lead, None, spec[-1])
             return NestedTensor(w_base=packed,
                                 deltas=tuple(packed for _ in leaf.deltas),
                                 scale=packed, shape=leaf.shape,

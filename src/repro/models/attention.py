@@ -14,6 +14,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..distributed.ctx import shard_hint
 
@@ -26,9 +27,18 @@ def _gqa_scores(q, k):
                       preferred_element_type=jnp.float32)
 
 
+def _in_window(qpos, kpos, window):
+    """(Sq, Skv) keys within ``window`` positions of their query, the
+    query's own position included."""
+    return kpos[None, :] > qpos[:, None] - window
+
+
 def full_attention(q, k, v, *, causal: bool, q_offset=0,
-                   kv_len: Optional[jax.Array] = None) -> jax.Array:
-    """Direct attention (small S / decode). q: (B,Sq,Hq,hd), k/v: (B,Skv,Hkv,hd)."""
+                   kv_len: Optional[jax.Array] = None,
+                   window: Optional[jax.Array] = None) -> jax.Array:
+    """Direct attention (small S / decode). q: (B,Sq,Hq,hd), k/v: (B,Skv,Hkv,hd).
+    ``window`` (a scalar, or None for none) masks keys more than
+    window - 1 positions before their query (query i at q_offset + i)."""
     B, Sq, Hq, hd = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
@@ -43,6 +53,9 @@ def full_attention(q, k, v, *, causal: bool, q_offset=0,
     if kv_len is not None:
         valid = jnp.arange(Skv) < kv_len
         s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
+    if window is not None:
+        near = _in_window(jnp.arange(Sq) + q_offset, jnp.arange(Skv), window)
+        s = jnp.where(near[None, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", p, v)
     return o.reshape(B, Sq, Hq, hd)
@@ -50,7 +63,7 @@ def full_attention(q, k, v, *, causal: bool, q_offset=0,
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def blockwise_attention(q, k, v, causal: bool = True,
-                        kv_block: int = 512) -> jax.Array:
+                        kv_block: int = 512, window=None) -> jax.Array:
     """Flash-semantics attention with a custom blockwise VJP.
 
     q: (B,S,Hq,hd), k/v: (B,S,Hkv,hd).  Neither direction materializes the
@@ -60,15 +73,16 @@ def blockwise_attention(q, k, v, causal: bool = True,
     which is what keeps 32k prefill training viable (a plain scan-of-softmax
     backward stores S*S/kv_block probability blocks and forces GSPMD into
     per-block regather - observed as the dominant collective in the naive
-    baseline; see EXPERIMENTS.md §Perf).
+    baseline; see EXPERIMENTS.md §Perf).  ``window`` as in
+    :func:`full_attention`.
     """
     if q.shape[1] % kv_block != 0:
-        return full_attention(q, k, v, causal=causal)
-    o, m, l = _flash_fwd_inner(q, k, v, causal, kv_block)
+        return full_attention(q, k, v, causal=causal, window=window)
+    o, m, l = _flash_fwd_inner(q, k, v, causal, kv_block, window)
     return o
 
 
-def _flash_fwd_inner(q, k, v, causal, kv_block):
+def _flash_fwd_inner(q, k, v, causal, kv_block, window=None):
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
@@ -88,6 +102,10 @@ def _flash_fwd_inner(q, k, v, causal, kv_block):
             kpos = j * kv_block + jnp.arange(kv_block)
             mask = kpos[None, :] <= qpos[:, None]          # (S, kv_block)
             s = jnp.where(mask[None, None, None], s, NEG_INF)
+        if window is not None:
+            near = _in_window(qpos, j * kv_block + jnp.arange(kv_block),
+                              window)
+            s = jnp.where(near[None, None, None], s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
@@ -107,20 +125,27 @@ def _flash_fwd_inner(q, k, v, causal, kv_block):
     return o, m, l
 
 
-def _flash_fwd(q, k, v, causal, kv_block):
+def _flash_fwd(q, k, v, causal, kv_block, window=None):
     if q.shape[1] % kv_block != 0:
-        o = full_attention(q, k, v, causal=causal)
-        return o, (q, k, v, o, None, None)
-    o, m, l = _flash_fwd_inner(q, k, v, causal, kv_block)
-    return o, (q, k, v, o, m, l)
+        o = full_attention(q, k, v, causal=causal, window=window)
+        return o, (q, k, v, o, None, None, window)
+    o, m, l = _flash_fwd_inner(q, k, v, causal, kv_block, window)
+    return o, (q, k, v, o, m, l, window)
+
+
+def _no_grad(window):
+    """The cotangent of the integer window: none."""
+    if window is None:
+        return None
+    return np.zeros(np.shape(window), jax.dtypes.float0)
 
 
 def _flash_bwd(causal, kv_block, res, do):
-    q, k, v, o, m, l = res
+    q, k, v, o, m, l, window = res
     if m is None:                       # small-shape fallback path
-        _, vjp = jax.vjp(lambda q, k, v: full_attention(q, k, v, causal=causal),
-                         q, k, v)
-        return vjp(do)
+        _, vjp = jax.vjp(lambda q, k, v: full_attention(
+            q, k, v, causal=causal, window=window), q, k, v)
+        return vjp(do) + (_no_grad(window),)
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
@@ -144,6 +169,10 @@ def _flash_bwd(causal, kv_block, res, do):
             kpos = j * kv_block + jnp.arange(kv_block)
             mask = kpos[None, :] <= qpos[:, None]
             s = jnp.where(mask[None, None, None], s, NEG_INF)
+        if window is not None:
+            near = _in_window(qpos, j * kv_block + jnp.arange(kv_block),
+                              window)
+            s = jnp.where(near[None, None, None], s, NEG_INF)
         p = jnp.exp(s - m[..., None]) * linv[..., None]     # normalized probs
         dv = jnp.einsum("bhgqk,bqhgd->bkhd", p, dog)        # sum over G, q
         dp = jnp.einsum("bqhgd,bkhd->bhgqk", dog, vj.astype(jnp.float32),
@@ -160,13 +189,15 @@ def _flash_bwd(causal, kv_block, res, do):
     dk = jnp.moveaxis(dks, 0, 1).reshape(B, S, Hkv, hd)
     dv = jnp.moveaxis(dvs, 0, 1).reshape(B, S, Hkv, hd)
     return (dq.reshape(B, S, Hq, hd).astype(q.dtype),
-            dk.astype(k.dtype), dv.astype(v.dtype))
+            dk.astype(k.dtype), dv.astype(v.dtype), _no_grad(window))
 
 
 blockwise_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def decode_attention(q, k_cache, v_cache, pos) -> jax.Array:
+def decode_attention(q, k_cache, v_cache, pos, window=None) -> jax.Array:
     """One-token attention. q: (B,1,Hq,hd); caches: (B,S,Hkv,hd); pos: scalar
-    index of the current token (entries <= pos are valid)."""
-    return full_attention(q, k_cache, v_cache, causal=False, kv_len=pos + 1)
+    index of the current token (entries <= pos are valid, and with a
+    ``window`` only the latest ``window`` of them)."""
+    return full_attention(q, k_cache, v_cache, causal=False, kv_len=pos + 1,
+                          q_offset=pos, window=window)

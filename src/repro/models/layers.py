@@ -1,6 +1,7 @@
 """Shared neural building blocks (norms, activations, RoPE, linear)."""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -39,8 +40,9 @@ def packed_linear(x: jax.Array, nt: NestedTensor, out_dtype=None) -> jax.Array:
     kernel (kernels/nested_matmul, the 2-stream fast path); deeper rungs
     take the general K-stream ladder kernel (DESIGN.md Sec. 8).  Pallas on
     TPU, jnp reference on CPU (same storage, same numbers).  Leaves with
-    stacked leading dims (e.g. MoE experts) fall back to on-the-fly
-    dequant inside the jit - still no host-side materialize."""
+    stacked leading dims fall back to on-the-fly dequant inside the jit;
+    the MoE serving path reads its expert stacks through
+    kernels/expert_matmul instead (models/moe.py)."""
     if nt.w_base.ndim != 2:
         return pdot(x, nt.dequant(x.dtype), preferred=out_dtype)
     r = nt.rung
@@ -107,13 +109,42 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def yarn_freqs(head_dim: int, theta: float, factor: float,
+               original_max_len: int, beta_fast: float,
+               beta_slow: float) -> jax.Array:
+    """YaRN inverse frequencies, as ``transformers`` defines them: the
+    interpolated (over ``factor``) and the extrapolated frequencies,
+    blended by a linear ramp between the correction dims of
+    ``beta_fast`` and ``beta_slow`` rotations in ``original_max_len``."""
+    def corr(rot):
+        return (head_dim * math.log(original_max_len / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    pos = theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    extrapolate = 1.0 - ramp
+    return ((1.0 / (factor * pos)) * (1.0 - extrapolate)
+            + (1.0 / pos) * extrapolate)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               freqs: Optional[jax.Array] = None,
+               scale: Optional[jax.Array] = None) -> jax.Array:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).
+    ``freqs`` (hd/2,) replaces the plain frequencies of ``theta`` and
+    ``scale`` multiplies cos and sin (YaRN's attention factor)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                   # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos = jnp.cos(ang)[..., None, :]                    # (..., S, 1, hd/2)
     sin = jnp.sin(ang)[..., None, :]
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

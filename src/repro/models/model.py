@@ -31,8 +31,9 @@ from ..core.nesting import NestedTensor
 from ..distributed.ctx import shard_hint
 from . import mamba2
 from .attention import blockwise_attention, decode_attention, full_attention
-from .layers import apply_rope, linear, mlp, norm, packed_linear, pdot
-from .moe import moe_ffn
+from .layers import (apply_rope, linear, mlp, norm, packed_linear, pdot,
+                     rope_freqs, yarn_freqs)
+from .moe import held_moe_ffn, moe_ffn
 
 
 def _dtype(cfg: ModelConfig):
@@ -96,15 +97,16 @@ def _mlp_init(key, cfg, in_dim: int, stacked: bool):
 
 
 def _moe_init(key, cfg):
-    L, E = cfg.num_layers, cfg.num_experts
+    """The router scores every expert; the stacks hold the held ones."""
+    L, E, n = cfg.num_layers, cfg.num_experts, cfg.held_experts
     dt = _dtype(cfg)
     ks = jax.random.split(key, 4)
     return {
         "router": {"w": _dense_init(ks[0], (L, cfg.d_model, E), jnp.float32)},
         "experts": {
-            "w_gate": {"w": _dense_init(ks[1], (L, E, cfg.d_model, cfg.d_ff), dt)},
-            "w_up": {"w": _dense_init(ks[2], (L, E, cfg.d_model, cfg.d_ff), dt)},
-            "w_down": {"w": _dense_init(ks[3], (L, E, cfg.d_ff, cfg.d_model), dt)},
+            "w_gate": {"w": _dense_init(ks[1], (L, n, cfg.d_model, cfg.d_ff), dt)},
+            "w_up": {"w": _dense_init(ks[2], (L, n, cfg.d_model, cfg.d_ff), dt)},
+            "w_down": {"w": _dense_init(ks[3], (L, n, cfg.d_ff, cfg.d_model), dt)},
         },
     }
 
@@ -169,6 +171,41 @@ def init_params(cfg: ModelConfig, rng) -> Dict:
 # ===========================================================================
 # Attention sub-block (dense / moe layers + zamba2 shared block)
 # ===========================================================================
+FULL_WINDOW = 1 << 30       # a full layer's window: every earlier position
+
+
+def layer_info(cfg: ModelConfig):
+    """Per-layer attention settings of a config with a layer pattern,
+    scanned beside the layer weights: RoPE frequencies ``freqs`` (L,
+    hd/2), cos/sin ``rope_scale`` (L,) and ``window`` (L,).  None for a
+    config without a pattern, whose layers then run today's code."""
+    if not cfg.layer_types:
+        return None
+    plain = rope_freqs(cfg.head_dim, cfg.rope_theta)
+    yarn = plain if not cfg.yarn_factor else yarn_freqs(
+        cfg.head_dim, cfg.rope_theta, cfg.yarn_factor,
+        cfg.yarn_original_max_len, cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    full = [cfg.layer_type(l) == "full" for l in range(cfg.num_layers)]
+    yarn_scale = cfg.yarn_attention_factor if cfg.yarn_factor else 1.0
+    return {
+        "freqs": jnp.stack([yarn if f else plain for f in full]),
+        "rope_scale": jnp.asarray([yarn_scale if f else 1.0 for f in full],
+                                  jnp.float32),
+        "window": jnp.asarray([FULL_WINDOW if f else cfg.sliding_window
+                               for f in full], jnp.int32),
+    }
+
+
+def _rope(x, positions, cfg, li):
+    if li is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    return apply_rope(x, positions, cfg.rope_theta, freqs=li["freqs"],
+                      scale=li["rope_scale"])
+
+
+def _window(li):
+    return None if li is None else li["window"]
+
 def _qkv(x, lp, cfg):
     q = linear(x, lp["q"]["w"], lp["q"].get("b"))
     k = linear(x, lp["k"]["w"], lp["k"].get("b"))
@@ -180,13 +217,14 @@ def _qkv(x, lp, cfg):
     return q, k, v
 
 
-def attn_seq(x, lp, cfg, kv_block: int = 512):
-    """Full-sequence causal attention. Returns (out, (k, v))."""
+def attn_seq(x, lp, cfg, kv_block: int = 512, li=None):
+    """Full-sequence causal attention. Returns (out, (k, v)).  ``li``:
+    this layer's row of :func:`layer_info`, or None."""
     B, S = x.shape[:2]
     q, k, v = _qkv(x, lp, cfg)
     pos = jnp.arange(S)
-    q = apply_rope(q, pos, cfg.rope_theta)
-    k = apply_rope(k, pos, cfg.rope_theta)
+    q = _rope(q, pos, cfg, li)
+    k = _rope(k, pos, cfg, li)
     # head-TP mode: heads -> model axis.  Sequence-parallel mode (head count
     # does not divide the model axis): q/o shard the sequence dim instead,
     # k/v replicate over model (small for GQA).
@@ -194,31 +232,31 @@ def attn_seq(x, lp, cfg, kv_block: int = 512):
     k = shard_hint(k, ("batch", None, "kv_heads", None))
     v = shard_hint(v, ("batch", None, "kv_heads", None))
     if S > 1024:
-        o = blockwise_attention(q, k, v, True, kv_block)
+        o = blockwise_attention(q, k, v, True, kv_block, _window(li))
     else:
-        o = full_attention(q, k, v, causal=True)
+        o = full_attention(q, k, v, causal=True, window=_window(li))
     o = shard_hint(o, ("batch", "attn_seq", "heads", None))
     o = o.reshape(B, S, cfg.num_heads * cfg.head_dim)
     return linear(o, lp["o"]["w"]), (k, v)
 
 
-def attn_decode(x, lp, cfg, k_cache, v_cache, pos):
+def attn_decode(x, lp, cfg, k_cache, v_cache, pos, li=None):
     """One-token attention against cache. x: (B,1,in_dim);
     caches: (B,Smax,Hkv,hd); pos: scalar. Returns (out, (k_cache, v_cache))."""
     B = x.shape[0]
     q, k, v = _qkv(x, lp, cfg)
-    q = apply_rope(q, jnp.full((1,), pos), cfg.rope_theta)
-    k = apply_rope(k, jnp.full((1,), pos), cfg.rope_theta)
+    q = _rope(q, jnp.full((1,), pos), cfg, li)
+    k = _rope(k, jnp.full((1,), pos), cfg, li)
     k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype),
                                            (0, pos, 0, 0))
     v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype),
                                            (0, pos, 0, 0))
-    o = decode_attention(q, k_cache, v_cache, pos)
+    o = decode_attention(q, k_cache, v_cache, pos, window=_window(li))
     o = o.reshape(B, 1, cfg.num_heads * cfg.head_dim)
     return linear(o, lp["o"]["w"]), (k_cache, v_cache)
 
 
-def attn_decode_chunk(x, lp, cfg, k_cache, v_cache, pos):
+def attn_decode_chunk(x, lp, cfg, k_cache, v_cache, pos, li=None):
     """S-token chunked attention against cache (speculative verify).
 
     x: (B,S,in_dim); caches: (B,Smax,Hkv,hd); pos: scalar start position.
@@ -230,13 +268,14 @@ def attn_decode_chunk(x, lp, cfg, k_cache, v_cache, pos):
     B, S = x.shape[:2]
     q, k, v = _qkv(x, lp, cfg)
     positions = pos + jnp.arange(S)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = _rope(q, positions, cfg, li)
+    k = _rope(k, positions, cfg, li)
     k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype),
                                            (0, pos, 0, 0))
     v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype),
                                            (0, pos, 0, 0))
-    o = full_attention(q, k_cache, v_cache, causal=True, q_offset=pos)
+    o = full_attention(q, k_cache, v_cache, causal=True, q_offset=pos,
+                       window=_window(li))
     o = o.reshape(B, S, cfg.num_heads * cfg.head_dim)
     return linear(o, lp["o"]["w"]), (k_cache, v_cache)
 
@@ -244,20 +283,28 @@ def attn_decode_chunk(x, lp, cfg, k_cache, v_cache, pos):
 # ===========================================================================
 # Transformer (dense / moe) forward
 # ===========================================================================
-def _ffn(h, lp, cfg, dropless=False):
-    if cfg.family == "moe":
-        y, aux = moe_ffn(h, lp["moe"], num_experts=cfg.num_experts,
-                         top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-                         act=cfg.act, dropless=dropless)
-        return y, aux
-    return mlp(h, lp["mlp"], cfg.act), 0.0
+def _ffn(h, lp, cfg, inference=False):
+    """(out, aux loss, rows per held expert or None).  MoE layers route
+    with the inference dispatch (exact, held experts only) on the
+    serving paths and with the capacity dispatch in training."""
+    if cfg.family != "moe":
+        return mlp(h, lp["mlp"], cfg.act), 0.0, None
+    if inference:
+        y, rows = held_moe_ffn(h, lp["moe"], num_experts=cfg.num_experts,
+                               top_k=cfg.top_k, first=cfg.expert_first,
+                               held=cfg.held_experts, act=cfg.act)
+        return y, 0.0, rows
+    y, aux = moe_ffn(h, lp["moe"], num_experts=cfg.num_experts,
+                     top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
+                     act=cfg.act)
+    return y, aux, None
 
 
-def _tf_layer_seq(h, lp, cfg, dropless=False):
-    a, kv = attn_seq(norm(h, lp["attn_norm"], cfg.norm), lp, cfg)
+def _tf_layer_seq(h, lp, cfg, li=None, inference=False):
+    a, kv = attn_seq(norm(h, lp["attn_norm"], cfg.norm), lp, cfg, li=li)
     h = h + a
-    y, aux = _ffn(norm(h, lp["mlp_norm"], cfg.norm), lp, cfg,
-                  dropless=dropless)
+    y, aux, _ = _ffn(norm(h, lp["mlp_norm"], cfg.norm), lp, cfg,
+                     inference=inference)
     h = h + y
     h = shard_hint(h, ("batch", None, None))
     return h, kv, aux
@@ -266,38 +313,57 @@ def _tf_layer_seq(h, lp, cfg, dropless=False):
 def transformer_seq(params, x, cfg, want_cache: bool):
     """x: (B,S,d) embedded input. Returns (h, cache, aux_sum).
 
-    Cache-building runs (prefill) route MoE layers droplessly so that
-    the subsequent cached decode reproduces them exactly; training
-    (want_cache=False) keeps the capacity-dropped dispatch."""
-    body = partial(_tf_layer_seq, dropless=want_cache)
+    Cache-building runs (prefill) route MoE layers with the exact
+    inference dispatch, so that the cached decode after them reproduces
+    them; training (want_cache=False) keeps the capacity-dropped
+    dispatch."""
+    body = partial(_tf_layer_seq, inference=want_cache)
     if cfg.remat:
         body = jax.checkpoint(body, static_argnums=(2,),
                               policy=jax.checkpoint_policies.nothing_saveable)
 
-    def scan_fn(carry, lp):
+    def scan_fn(carry, xs):
         h, aux = carry
-        h, kv, aux_l = body(h, lp, cfg)
+        lp, li = xs
+        h, kv, aux_l = body(h, lp, cfg, li)
         ys = kv if want_cache else None
         return (h, aux + aux_l), ys
 
-    (h, aux), kvs = jax.lax.scan(scan_fn, (x, 0.0), params["blocks"])
+    (h, aux), kvs = jax.lax.scan(scan_fn, (x, 0.0),
+                                 (params["blocks"], layer_info(cfg)))
     cache = None
     if want_cache:
         cache = {"k": kvs[0], "v": kvs[1]}    # (L,B,S,Hkv,hd)
     return h, cache, aux
 
 
-def transformer_decode(params, x, cfg, cache, pos):
+def _cached_layers(params, x, cfg, cache, attend):
+    """Run every layer against the cache with ``attend`` (one token or a
+    chunk); a cache with ``expert_rows`` adds each layer's count of rows
+    routed to its held experts."""
     def scan_fn(h, xs):
-        lp, kc, vc = xs
-        a, (kc, vc) = attn_decode(norm(h, lp["attn_norm"], cfg.norm), lp, cfg,
-                                  kc, vc, pos)
+        lp, kc, vc, li = xs
+        a, (kc, vc) = attend(norm(h, lp["attn_norm"], cfg.norm), lp, cfg,
+                             kc, vc, li)
         h = h + a
-        y, _ = _ffn(norm(h, lp["mlp_norm"], cfg.norm), lp, cfg, dropless=True)
-        return h + y, (kc, vc)
+        y, _, rows = _ffn(norm(h, lp["mlp_norm"], cfg.norm), lp, cfg,
+                          inference=True)
+        return h + y, (kc, vc, rows)
 
-    h, (kc, vc) = jax.lax.scan(scan_fn, x, (params["blocks"], cache["k"], cache["v"]))
-    return h, {"k": kc, "v": vc}
+    h, (kc, vc, rows) = jax.lax.scan(
+        scan_fn, x, (params["blocks"], cache["k"], cache["v"],
+                     layer_info(cfg)))
+    new = {"k": kc, "v": vc}
+    if "expert_rows" in cache:
+        new["expert_rows"] = cache["expert_rows"] + rows
+    return h, new
+
+
+def transformer_decode(params, x, cfg, cache, pos):
+    return _cached_layers(
+        params, x, cfg, cache,
+        lambda xn, lp, cfg, kc, vc, li: attn_decode(xn, lp, cfg, kc, vc, pos,
+                                                    li))
 
 
 def transformer_decode_chunk(params, x, cfg, cache, pos):
@@ -306,16 +372,10 @@ def transformer_decode_chunk(params, x, cfg, cache, pos):
     The verify half of self-speculative decoding: one weight-streaming
     pass scores every drafted position, where sequential decode would
     stream the full-bit weights S times."""
-    def scan_fn(h, xs):
-        lp, kc, vc = xs
-        a, (kc, vc) = attn_decode_chunk(norm(h, lp["attn_norm"], cfg.norm),
-                                        lp, cfg, kc, vc, pos)
-        h = h + a
-        y, _ = _ffn(norm(h, lp["mlp_norm"], cfg.norm), lp, cfg, dropless=True)
-        return h + y, (kc, vc)
-
-    h, (kc, vc) = jax.lax.scan(scan_fn, x, (params["blocks"], cache["k"], cache["v"]))
-    return h, {"k": kc, "v": vc}
+    return _cached_layers(
+        params, x, cfg, cache,
+        lambda xn, lp, cfg, kc, vc, li: attn_decode_chunk(xn, lp, cfg, kc,
+                                                          vc, pos, li))
 
 
 # ===========================================================================
@@ -558,6 +618,10 @@ def make_model(cfg: ModelConfig) -> Model:
             shp = (L, batch_size, max_len, cfg.num_kv_heads, cfg.head_dim)
             cache["k"] = jnp.zeros(shp, dt)
             cache["v"] = jnp.zeros(shp, dt)
+            if cfg.family == "moe":
+                # rows routed to each held expert, summed over decode steps
+                cache["expert_rows"] = jnp.zeros((L, cfg.held_experts),
+                                                 jnp.int32)
         else:
             H, P, N = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
             conv_dim = cfg.d_inner + 2 * N

@@ -1,4 +1,10 @@
-"""Mixture-of-Experts FFN: top-k routing with sort-based capacity dispatch.
+"""Mixture-of-Experts FFN: top-k routing, sort-based dispatch.
+
+Two dispatches live here.  Training (``moe_ffn``) keeps the capacity
+dispatch described next.  Inference (``held_moe_ffn``: prefill, decode
+and the speculative verify) routes over every expert but computes only
+the experts this device holds, on ragged groups of rows sorted by
+expert, straight from the packed expert stacks (kernels/expert_matmul).
 
 GShard/Switch-style "dropping" implementation, XLA/TPU-friendly: tokens are
 argsorted by assigned expert, given a position-in-expert via the sorted
@@ -29,21 +35,22 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..core.decompose import delta_bits
+from ..core.nesting import NestedTensor
 from ..distributed import ctx
 from ..distributed.ctx import shard_hint
+from ..kernels.expert_matmul import ops as expert_ops
 from .layers import linear, pdot, resolve_weight, silu
+
+# tokens routed in one pass of the inference dispatch: a longer input is
+# cut into passes, so the padded row buffer stays within a few hundred MB
+HELD_PASS_TOKENS = 8192
 
 
 def capacity(tokens: int, num_experts: int, top_k: int, factor: float,
-             multiple: int = 8, dropless: bool = False) -> int:
-    """Per-expert slot count C.  ``dropless=True`` sizes C for the worst
-    case (every assignment lands on one expert), so NO token can ever be
-    dropped - the exact-routing mode inference paths use so that a cached
-    decode reproduces the full forward bit-for-bit."""
-    if dropless:
-        c = tokens * top_k
-    else:
-        c = math.ceil(tokens * top_k * factor / num_experts)
+             multiple: int = 8) -> int:
+    """Per-expert slot count C of the training dispatch."""
+    c = math.ceil(tokens * top_k * factor / num_experts)
     return max(multiple, math.ceil(c / multiple) * multiple)
 
 
@@ -94,16 +101,11 @@ def _combine(y, table, gates, T: int, d: int):
 # ---------------------------------------------------------------------------
 def moe_ffn(x: jax.Array, params: Dict, *, num_experts: int, top_k: int,
             capacity_factor: float, act: str = "swiglu",
-            cap_multiple: int = 8,
-            dropless: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (out, aux_loss).
-
-    ``dropless=True`` (the inference-path setting) gives every expert
-    enough capacity for the worst-case assignment, so routing is exact:
-    the per-token output no longer depends on which OTHER tokens share
-    the batch, and a one-token cached decode equals the full-sequence
-    forward.  Training keeps the capacity-dropped GShard dispatch (the
-    standard efficiency trade)."""
+            cap_multiple: int = 8) -> Tuple[jax.Array, jax.Array]:
+    """x: (B, S, d) -> (out, aux_loss), the training dispatch: capacity
+    C per expert, assignments past it dropped (the standard GShard
+    efficiency trade).  Inference routes exactly with
+    :func:`held_moe_ffn`."""
     B, S, d = x.shape
     T = B * S
     E, K = num_experts, top_k
@@ -123,8 +125,7 @@ def moe_ffn(x: jax.Array, params: Dict, *, num_experts: int, top_k: int,
         for a in dp_axes:
             dpsz *= mesh.shape[a]
         if T % dpsz == 0:
-            C_loc = capacity(T // dpsz, E, K, capacity_factor, cap_multiple,
-                             dropless=dropless)
+            C_loc = capacity(T // dpsz, E, K, capacity_factor, cap_multiple)
             xg, table, gates, aux = _sharded_dispatch(
                 mesh, dp_axes, xf, rw, E=E, K=K, C=C_loc)
             y = _expert_compute(xg, params, act, x.dtype)
@@ -134,7 +135,7 @@ def moe_ffn(x: jax.Array, params: Dict, *, num_experts: int, top_k: int,
             return out.reshape(B, S, d), aux
         # fall through to the global path when tokens don't split evenly
 
-    C = capacity(T, E, K, capacity_factor, cap_multiple, dropless=dropless)
+    C = capacity(T, E, K, capacity_factor, cap_multiple)
     xg, table, gates, aux = _dispatch(xf, rw, E=E, K=K, C=C)
     xg = shard_hint(xg, ("experts", "expert_cap", None))
     y = _expert_compute(xg, params, act, x.dtype)
@@ -184,3 +185,158 @@ def _sharded_combine(mesh, dp_axes, y, table, gates, *, T_loc, d):
         out_specs=P(dp, None),
         check_vma=False,
     )(y, table, gates)
+
+
+# ---------------------------------------------------------------------------
+# inference: the held experts on ragged groups, from the packed stacks
+# ---------------------------------------------------------------------------
+def route(xf, router_w, top_k: int):
+    """Softmax over every expert, top-k, renormalised: (gates (T, K) f32,
+    experts (T, K) int32)."""
+    logits = jnp.matmul(xf.astype(jnp.float32),
+                        router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, top_k)
+    return gates / jnp.sum(gates, axis=-1, keepdims=True), experts
+
+
+def grouped_matmul(x, w, plan: expert_ops.GroupPlan):
+    """Rows of ``x`` laid out by ``plan`` times their expert's weight of
+    the (n, K, N) stack ``w``: packed NestQuant streams at the stamped
+    rung, or a float stack."""
+    if not isinstance(w, NestedTensor):
+        return jax.lax.ragged_dot(x, w.astype(x.dtype),
+                                  plan.sizes).astype(x.dtype)
+    r = w.rung
+    return expert_ops.expert_matmul(
+        x, (w.w_base,) + tuple(w.deltas[:r]), w.rung_scale(r), plan,
+        bits=w.bits[:r + 1], widths=(w.bits[0],) + delta_bits(w.bits)[:r],
+        K=w.K, block_k=w.block, out_dtype=x.dtype)
+
+
+def _held_pass(xf, params, *, num_experts: int, top_k: int, first,
+               held: int, act: str, out_dtype):
+    """One pass of ``held_moe_ffn`` over xf (T, d): (out (T, d),
+    rows (held,))."""
+    T, d = xf.shape
+    E, K, n = num_experts, top_k, held
+    gates, experts = route(xf, params["router"]["w"], K)
+    local = experts - first
+    mine = (local >= 0) & (local < n)
+    group = jnp.where(mine, local, n).reshape(T * K)   # n: not held here
+    sizes = jnp.sum(jax.nn.one_hot(group, n, dtype=jnp.int32), axis=0)
+    tm = expert_ops.row_tile(T * K / E)
+    tiles = -(-(T * min(K, n) + n * (tm - 1)) // tm)
+    plan = expert_ops.group_plan(sizes, tm, tiles)
+    P = tiles * tm
+    # the assignments in expert order, and the padded row of each
+    order = jnp.argsort(group, stable=True)
+    sg = group[order]
+    held_g = jnp.minimum(sg, n - 1)
+    rank = jnp.arange(T * K, dtype=jnp.int32) - (
+        jnp.cumsum(sizes) - sizes)[held_g]
+    row = jnp.where(sg < n, plan.starts[held_g] + rank, P)
+    token = jnp.full((P + 1,), T, jnp.int32).at[row].set(
+        (order // K).astype(jnp.int32))[:P]
+    xs = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)], axis=0)[token]
+    ex = params["experts"]
+    if act == "swiglu":
+        h = silu(grouped_matmul(xs, ex["w_gate"]["w"], plan)) * \
+            grouped_matmul(xs, ex["w_up"]["w"], plan)
+    else:
+        h = jax.nn.gelu(grouped_matmul(xs, ex["w_up"]["w"], plan))
+    y = grouped_matmul(h.astype(xf.dtype), ex["w_down"]["w"], plan)
+    # each assignment reads its row back, one of the k at a time; rows
+    # not held here add nothing
+    at = jnp.zeros((T * K,), jnp.int32).at[order].set(
+        jnp.minimum(row, P - 1)).reshape(T, K)
+    out = jnp.zeros((T, d), jnp.float32)
+    for k in range(K):
+        yk = jnp.where(mine[:, k, None], y[at[:, k]], 0)
+        out = out + yk.astype(jnp.float32) * gates[:, k, None]
+    return out.astype(out_dtype), sizes
+
+
+def _held_tokens(xf, params, **kw):
+    """``_held_pass`` over xf (T, d) in passes of at most
+    HELD_PASS_TOKENS tokens: (out (T, d), rows (held,))."""
+    T, d = xf.shape
+    passes = -(-T // HELD_PASS_TOKENS)
+    while T % passes:
+        passes += 1
+    if passes == 1:
+        return _held_pass(xf, params, **kw)
+    out, rows = jax.lax.map(partial(_held_pass, params=params, **kw),
+                            xf.reshape(passes, T // passes, d))
+    return out.reshape(T, d), jnp.sum(rows, axis=0)
+
+
+def _expert_parallel(T: int, held: int):
+    """(mesh, data axes or None, expert axis) when a mesh context shards
+    the held experts evenly over an axis and the tokens over the data
+    axes, else None."""
+    cur = ctx.current()
+    if cur is None:
+        return None
+    mesh, rules = cur
+    ep = rules.get("experts")
+    if not ep or held % mesh.shape[ep]:
+        return None
+    dp = rules.get("batch")
+    dp = (dp if isinstance(dp, tuple) else (dp,)) if dp else ()
+    dpsz = math.prod(mesh.shape[a] for a in dp)
+    if T % dpsz:
+        return None
+    return mesh, dp, ep
+
+
+def held_moe_ffn(x: jax.Array, params: Dict, *, num_experts: int,
+                 top_k: int, first: int = 0, held: int = 0,
+                 act: str = "swiglu") -> Tuple[jax.Array, jax.Array]:
+    """x: (B, S, d) -> (out, rows), the inference dispatch.
+
+    The router scores all ``num_experts`` experts; this device holds
+    experts [first, first + held) (``held`` 0: all of them), stacked on
+    the leading axis of ``params["experts"]``.  Each token's top-k
+    assignments that fall on a held expert are sorted by expert into
+    ragged groups, each padded to the row tile, and computed by the
+    grouped matmul; the output is their gate-weighted sum, and what the
+    experts held elsewhere would add is left out.  Routing is exact and
+    per token, so a token's output does not depend on the batch around
+    it.  ``rows`` (held,) counts the assignments each held expert took.
+
+    Under a mesh whose rules put ``experts`` on an axis (expert
+    parallelism), each device of that axis holds its slice of the held
+    stacks and routes its data shard's tokens over every expert; the
+    slices' partial sums meet in one f32 ``psum`` over the expert axis,
+    so no device gathers another's experts."""
+    B, S, d = x.shape
+    T = B * S
+    n = held or num_experts
+    xf = x.reshape(T, d)
+    kw = dict(num_experts=num_experts, top_k=top_k, act=act)
+    layout = _expert_parallel(T, n)
+    if layout is None:
+        out, rows = _held_tokens(xf, params, first=first, held=n,
+                                 out_dtype=x.dtype, **kw)
+        return out.reshape(B, S, d), rows
+    mesh, dp, ep = layout
+    n_loc = n // mesh.shape[ep]
+    tok = P(dp or None, None)
+
+    def local(xf_loc, p_loc):
+        f = first + jax.lax.axis_index(ep) * n_loc
+        out, rows = _held_tokens(xf_loc, p_loc, first=f, held=n_loc,
+                                 out_dtype=jnp.float32, **kw)
+        out = jax.lax.psum(out, ep)
+        if dp:
+            rows = jax.lax.psum(rows, dp)
+        return out.astype(xf_loc.dtype), rows
+
+    spec = {"router": jax.tree.map(lambda _: P(), params["router"]),
+            "experts": jax.tree.map(lambda _: P(ep), params["experts"])}
+    out, rows = jax.shard_map(
+        local, mesh=mesh, in_specs=(tok, spec), out_specs=(tok, P(ep)),
+        check_vma=False)(xf, params)
+    return out.reshape(B, S, d), rows

@@ -13,7 +13,8 @@ dispatch; the device's side is in the trace beside it.
 The spans, what each covers, and its arguments:
 
 - ``nq.generate``: all of ``ServeEngine.generate``.  ``batch`` (the
-  engine's count of earlier ``generate`` calls), ``rows``, ``real_rows``
+  engine's count of earlier ``generate`` calls), ``experts_held`` (0
+  for a model without experts), ``rows``, ``real_rows``
   (rows with uid >= 0), ``prompt_len``, ``steps``, ``rung`` (the rung
   served, set once the mode is ensured).
 - ``nq.ensure_mode``: ``ServeEngine.ensure_mode``, the policy's decision
@@ -35,6 +36,16 @@ The spans, what each covers, and its arguments:
   (``Artifact.read_array``).  ``nbytes``.
 - ``nq.page_in.put``: one fetched delta stream put on the device
   (``FilePager.fetch``).  ``nbytes``.
+- ``nq.expert_load``: the one transfer of a batch's routing count (MoE
+  only), after its last token is read.  ``held``, and ``rows_max`` and
+  ``rows_mean``: the rows one decode step routed to a held expert of a
+  layer, the most and the mean over layers and held experts.
+
+The packed kernels show in the device trace as custom calls named after
+their entry: ``packed_matmul``, ``nested_matmul`` and ``ladder_matmul``
+(2-D weights at rung 0, 1 and deeper), and ``expert_packed_matmul``,
+``expert_nested_matmul`` and ``expert_ladder_matmul`` (the grouped
+matmul over an expert stack, ``kernels/expert_matmul``).
 """
 from __future__ import annotations
 

@@ -128,6 +128,9 @@ class EngineStats:
     # device->host transfers of decoded tokens: one per plain decode step,
     # whatever the number of rows
     token_pulls: int = 0
+    # rows routed to each held expert, (layers, held experts), summed
+    # over the plain decode steps of the engine's life (MoE only)
+    expert_rows: Optional[np.ndarray] = None
 
     @property
     def spec_acceptance(self) -> float:
@@ -520,6 +523,8 @@ class ServeEngine:
         The call is one ``nq.generate`` span, with the spans of its
         phases inside (``repro.obs``)."""
         with obs.span("generate", batch=self.stats.prefills,
+                      experts_held=(self.cfg.held_experts
+                                    if self.cfg.family == "moe" else 0),
                       rows=len(requests),
                       real_rows=sum(r.uid >= 0 for r in requests),
                       prompt_len=max((len(r.prompt) for r in requests),
@@ -604,9 +609,22 @@ class ServeEngine:
                 for i, r in live:
                     r.out_tokens.append(int(host[i, 0]))
             next_tok = nxt
+        if "expert_rows" in cache:
+            self._expert_load(cache["expert_rows"], n_steps)
         self.last_profile = DecodeProfile(
             steps=n_steps, verify_bytes=self.store.resident_bytes())
         return requests
+
+    def _expert_load(self, rows, steps: int) -> None:
+        """Read the batch's count of rows routed to each held expert, in
+        one transfer once its last token is read, and keep it."""
+        with obs.span("expert_load", held=int(rows.shape[-1])) as span:
+            rows = np.asarray(rows)
+            per_step = rows / max(steps, 1)
+            span.set_metadata(rows_max=float(per_step.max()),
+                              rows_mean=float(per_step.mean()))
+        total = self.stats.expert_rows
+        self.stats.expert_rows = rows if total is None else total + rows
 
 
 class SpeculativeDecoder:

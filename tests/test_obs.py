@@ -78,7 +78,7 @@ def test_generate_records_one_span_with_a_token_sync_and_step_per_step(
     _, a, b, args, thread = gen[0]
     assert args == {"batch": 1, "rows": B, "real_rows": B - 1,
                     "prompt_len": PROMPT, "steps": STEPS,
-                    "rung": eng.store.rung}
+                    "rung": eng.store.rung, "experts_held": 0}
     for name in ("nq.token_sync", "nq.decode_step"):
         inner = [s for s in spans if s[0] == name]
         assert [s[3]["step"] for s in inner] == list(range(STEPS))
